@@ -4,15 +4,18 @@ The CI ``router-smoke`` job's scenario, runnable by hand:
 
 1. launches ``python -m repro.frontend.http_server --replicas 2`` as a real
    subprocess (its own process, own engines, SIGINT-driven lifecycle);
-2. replays a shared-prefix workload through the HTTP client and checks the
-   SSE token streams are **bit-identical** to an in-process single-engine
-   run of the same prompts (replicas share seed-0 params, so routing must
-   never change greedy tokens);
+2. replays a shared-prefix workload through the HTTP client;
 3. cancels a request mid-stream over HTTP and checks it aborts server-side;
 4. reads ``GET /v1/stats`` and checks the router's prefix directory took
    hits (the shared stream landed on its holder) and that every replica
    kept the one-readback-per-round zero-sync invariant;
-5. sends SIGINT and checks the server drains gracefully and exits 0.
+5. sends SIGINT and checks the server drains gracefully and exits 0;
+6. only then runs the same prompts on an in-process single engine and checks
+   the SSE token streams were **bit-identical** to it (replicas share seed-0
+   params, so routing must never change greedy tokens).
+
+The parent touches no device until the server has exited: an accelerator
+belongs to one process at a time. The child inherits ``JAX_PLATFORMS``.
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python examples/router_smoke.py
 """
@@ -35,7 +38,6 @@ from repro.frontend.http_server import build_backend  # noqa: E402
 
 def launch_server(replicas: int = 2) -> subprocess.Popen:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
@@ -67,30 +69,20 @@ def main() -> None:
     system = rng.integers(1, 1000, 48).tolist()
     prompts = [system + rng.integers(1, 1000, 16).tolist() for _ in range(5)]
 
-    # in-process single-engine reference (same prompts, same seed-0 params):
-    # the parity bar every HTTP/SSE stream must hit bit-for-bit
-    ref_backend = build_backend(replicas=1, kv_tokens=2048, max_budget=256)
-    reference = [ref_backend.submit(np.asarray(p, np.int32),
-                                    max_output=5).result() for p in prompts]
-    ref_backend.close()
-
     proc = launch_server(replicas=2)
     try:
         port = wait_banner(proc)
         cli = EngineHttpClient(port=port, timeout=180.0)
         cli.wait_ready(60.0)
 
-        # --- SSE parity: sequential shared-prefix stream ---------------------
+        # --- shared-prefix stream over SSE -----------------------------------
         # (sequential so each request's pages are committed — and in the
         # directory — before the next one routes)
-        for i, p in enumerate(prompts):
+        streamed = []
+        for p in prompts:
             h = cli.generate(p, slo_class="interactive", max_output=5)
-            toks = h.result()
-            assert toks == reference[i], \
-                f"prompt {i}: HTTP {toks} != in-process {reference[i]}"
+            streamed.append(h.result())
             assert h.finish_reason == "length", h.finish_reason
-        print(f"parity OK: {len(prompts)} SSE streams bit-identical "
-              f"to the in-process engine")
 
         # --- mid-stream cancel over HTTP -------------------------------------
         h = cli.generate(rng.integers(1, 1000, 64).tolist(), max_output=256)
@@ -130,6 +122,18 @@ def main() -> None:
         if proc.poll() is None:
             proc.kill()
             proc.wait(timeout=10)
+
+    # --- SSE parity against an in-process single engine ----------------------
+    # (same prompts, same seed-0 params; built only now that the server child
+    # has exited and released the device)
+    ref_backend = build_backend(replicas=1, kv_tokens=2048, max_budget=256)
+    reference = [ref_backend.submit(np.asarray(p, np.int32),
+                                    max_output=5).result() for p in prompts]
+    ref_backend.close()
+    for i, (toks, ref) in enumerate(zip(streamed, reference)):
+        assert toks == ref, f"prompt {i}: HTTP {toks} != in-process {ref}"
+    print(f"parity OK: {len(prompts)} SSE streams bit-identical "
+          f"to the in-process engine")
     print("ROUTER SMOKE PASSED")
 
 

@@ -1,6 +1,7 @@
-"""llama3.2-3b — small llama3 [hf:meta-llama/Llama-3.2-1B; unverified].
+"""llama3.2-3b — Llama 3.2 3B [hf:meta-llama/Llama-3.2-3B config.json].
 
-28L d_model=3072 24H (GQA kv=8) d_ff=8192 vocab=128256.
+28L d_model=3072 24H (GQA kv=8, head_dim 128) d_ff=8192 vocab=128256,
+rope_theta 500000, tied input/output embeddings.
 """
 from repro.configs.base import ATTN, DENSE, ModelConfig
 

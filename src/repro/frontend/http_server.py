@@ -90,7 +90,6 @@ class HttpFrontend:
         self.backend = backend
         self.host, self.port = host, port
         self.drain_s = drain_s
-        self._rid = 0
         self._queues: Dict[int, asyncio.Queue] = {}
         self._stopping = False
         self._stop_event: Optional[asyncio.Event] = None
@@ -219,8 +218,9 @@ class HttpFrontend:
             await self._respond(writer, 400, {"error": "prompt must be a "
                                                        "non-empty id list"})
             return
-        rid = self._rid
-        self._rid += 1
+        # the backend owns the rid space (it may have served requests before
+        # this front door attached)
+        rid = self.backend.alloc_rid()
         # queue registered BEFORE submit: QUEUED fires synchronously inside
         # submit and must not be lost (single loop thread -> no race)
         q: asyncio.Queue = asyncio.Queue()
@@ -396,6 +396,8 @@ def main(argv=None):
     ap.add_argument("--drain-s", type=float, default=30.0,
                     help="graceful-shutdown drain deadline on SIGINT")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     backend = build_backend(
         arch=args.arch, smoke=args.smoke, replicas=args.replicas,

@@ -224,7 +224,7 @@ class EngineRouter:
         return idx
 
     # ---- submission ----------------------------------------------------------
-    def _alloc_rid(self) -> int:
+    def alloc_rid(self) -> int:
         rid = self._next_rid
         self._next_rid += 1
         return rid
@@ -236,7 +236,7 @@ class EngineRouter:
         """Route and submit a prompt; returns the target replica's stream
         handle (its ``tokens()`` pumps that replica)."""
         prompt = np.asarray(prompt, np.int32)
-        rid = self._alloc_rid() if rid is None else rid
+        rid = self.alloc_rid() if rid is None else rid
         self._next_rid = max(self._next_rid, rid + 1)
         # placement needs the request's class rank and size before the
         # Request object exists; resolve the class the same way submit() does

@@ -206,26 +206,27 @@ def _fused_kernel(bt_ref, len_ref,   # scalar prefetch: [B, n], [B]
         if window > 0:
             mask &= k_pos >= length - window
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        # row statistics ride as [G, 1] columns so the partial outputs can
+        # keep a trailing singleton (a legal TPU block) without a relayout.
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1)
-        acc_new = acc_prev * corr[:, None] + jax.lax.dot_general(
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc_prev * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
     m, l, acc = jax.lax.fori_loop(
         0, pages_needed, body,
-        (jnp.full((G,), NEG_INF, jnp.float32), jnp.zeros((G,), jnp.float32),
-         jnp.zeros((G, D), jnp.float32)))
+        (jnp.full((G, 1), NEG_INF, jnp.float32),
+         jnp.zeros((G, 1), jnp.float32), jnp.zeros((G, D), jnp.float32)))
     if partial:
         o_ref[0, 0] = acc
         m_out[0, 0] = m
         l_out[0, 0] = l
     else:
-        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_fused(
@@ -272,14 +273,16 @@ def paged_attention_fused(
         dma_depth=dma_depth)
 
     if partial:
+        # m/l carry a trailing singleton: a (1, 1, G) block over (B, Hkv, G)
+        # is refused by the TPU compiler (the last two block dims must tile
+        # by (8, 128) or equal the array's), while (G, 1) equals it.
+        stat = pl.BlockSpec((1, 1, G, 1), lambda b, h, bt, L: (b, h, 0, 0))
         out_shape = (jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
-                     jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
-                     jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32))
+                     jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32))
         out_specs = (
             pl.BlockSpec((1, 1, G, D), lambda b, h, bt, L: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, bt, L: (b, h, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, bt, L: (b, h, 0)),
-        )
+            stat, stat)
     else:
         out_shape = jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype)
         out_specs = pl.BlockSpec((1, 1, G, D),
@@ -290,7 +293,7 @@ def paged_attention_fused(
         grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, bt, L: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=out_specs,
         scratch_shapes=[

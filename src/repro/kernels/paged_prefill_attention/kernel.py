@@ -246,26 +246,26 @@ def _fused_kernel(bt_ref, pos_ref, len_ref,   # scalar prefetch [R,n],[R],[R]
         if window > 0:
             mask &= q_pos - k_pos < window
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        # [bq*G, 1] row statistics: see the decode kernel.
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1)
-        acc_new = acc_prev * corr[:, None] + jax.lax.dot_general(
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc_prev * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
     m, l, acc = jax.lax.fori_loop(
         j_lo, j_hi, body,
-        (jnp.full((BG,), NEG_INF, jnp.float32), jnp.zeros((BG,), jnp.float32),
-         jnp.zeros((BG, D), jnp.float32)))
+        (jnp.full((BG, 1), NEG_INF, jnp.float32),
+         jnp.zeros((BG, 1), jnp.float32), jnp.zeros((BG, D), jnp.float32)))
     if partial:
         o_ref[0, 0] = acc
         m_out[0, 0] = m
         l_out[0, 0] = l
     else:
-        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_prefill_attention_fused(
@@ -314,18 +314,18 @@ def paged_prefill_attention_fused(
         partial=partial, dma_depth=dma_depth)
 
     if partial:
+        # trailing singleton on m/l keeps the block legal for the TPU
+        # compiler (see the decode kernel).
+        stat = pl.BlockSpec((1, 1, block_q * G, 1),
+                            lambda r, h, i, bt, pos, L: (r, h, i, 0))
         out_shape = (
             jax.ShapeDtypeStruct((R, Hkv, Sq * G, D), jnp.float32),
-            jax.ShapeDtypeStruct((R, Hkv, Sq * G), jnp.float32),
-            jax.ShapeDtypeStruct((R, Hkv, Sq * G), jnp.float32))
+            jax.ShapeDtypeStruct((R, Hkv, Sq * G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, Hkv, Sq * G, 1), jnp.float32))
         out_specs = (
             pl.BlockSpec((1, 1, block_q * G, D),
                          lambda r, h, i, bt, pos, L: (r, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q * G),
-                         lambda r, h, i, bt, pos, L: (r, h, i)),
-            pl.BlockSpec((1, 1, block_q * G),
-                         lambda r, h, i, bt, pos, L: (r, h, i)),
-        )
+            stat, stat)
     else:
         out_shape = jax.ShapeDtypeStruct((R, Hkv, Sq * G, D), q.dtype)
         out_specs = pl.BlockSpec((1, 1, block_q * G, D),
@@ -337,7 +337,7 @@ def paged_prefill_attention_fused(
         in_specs=[
             pl.BlockSpec((1, 1, block_q * G, D),
                          lambda r, h, i, bt, pos, L: (r, h, i, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -360,5 +360,5 @@ def paged_prefill_attention_fused(
 
     if partial:
         acc, m, l = out
-        return _rows(acc), _rows(m), _rows(l)
+        return _rows(acc), _rows(m[..., 0]), _rows(l[..., 0])
     return _rows(out)

@@ -1,25 +1,19 @@
-"""shard_map compatibility shim + mesh helpers for sharded kernel dispatch.
+"""shard_map wrapper + mesh helpers for sharded kernel dispatch.
 
 The serving executor runs the fused paged steps under ``jax.jit`` on a mesh;
 inside those steps the attention ops are the only mesh-aware computation
 (everything else is replicated math on replicated operands). The ops modules
-use :func:`shard_map` from here so one jax-version shim covers MoE expert
-parallelism and the paged-attention shards alike.
+use :func:`shard_map` from here, so MoE expert parallelism and the
+paged-attention shards share one default (``check_vma=False``).
 """
 from __future__ import annotations
 
-try:  # jax >= 0.5 exports shard_map at top level (``check_vma`` kwarg)
-    from jax import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # older jax (0.4.x): experimental module, ``check_rep`` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KW = "check_rep"
+import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    kw = {_SHARD_MAP_CHECK_KW: check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(mesh, axis: str) -> int:

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import SlidingServeScheduler
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import add_mesh_argument, make_serving_mesh
 from repro.serving.engine import EngineCore
 from repro.serving.request import Request
@@ -82,6 +83,7 @@ def main(argv=None):
                          "behind the prefix-affine router")
     add_mesh_argument(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.serve_http:
         # the network front door owns engine construction (it builds N

@@ -201,7 +201,7 @@ class InferenceServer:
         server's own counter."""
         cls = self.slo_classes[slo_class]
         prompt = np.asarray(prompt, np.int32)
-        req = Request(rid=self._alloc_rid() if rid is None else rid,
+        req = Request(rid=self.alloc_rid() if rid is None else rid,
                       arrival=self.core.now(),
                       prompt_len=len(prompt), max_output=max_output,
                       ttft_slo=cls.ttft_slo, tbt_slo=cls.tbt_slo,
@@ -227,7 +227,7 @@ class InferenceServer:
         self.core.add_request(req, prompt)
         return handle
 
-    def _alloc_rid(self) -> int:
+    def alloc_rid(self) -> int:
         rid = self._next_rid
         self._next_rid += 1
         return rid

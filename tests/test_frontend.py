@@ -258,9 +258,7 @@ def test_router_parity_with_single_engine(cfg):
 # HTTP/SSE transport (in-thread server; the subprocess path is
 # examples/router_smoke.py)
 # ---------------------------------------------------------------------------
-@pytest.fixture()
-def http_fe(cfg):
-    backend = build_backend(replicas=1, kv_tokens=2048, max_budget=256)
+def _start_http(backend):
     fe = HttpFrontend(backend, port=0, drain_s=30.0)
     th = threading.Thread(target=lambda: asyncio.run(fe.serve_forever()),
                           daemon=True)
@@ -271,10 +269,36 @@ def http_fe(cfg):
         time.sleep(0.02)
     cli.port = fe.port
     cli.wait_ready(60.0)
-    yield fe, cli, backend
+    return fe, cli, th
+
+
+def _stop_http(fe, th):
     fe.request_stop()
     th.join(timeout=60.0)
     assert not th.is_alive(), "HTTP server failed to drain on stop"
+
+
+@pytest.fixture()
+def http_fe(cfg):
+    backend = build_backend(replicas=1, kv_tokens=2048, max_budget=256)
+    fe, cli, th = _start_http(backend)
+    yield fe, cli, backend
+    _stop_http(fe, th)
+
+
+def test_http_front_door_after_inprocess_requests(cfg):
+    """A front door attached to a server that already served requests takes
+    rids from the server, so its requests never reuse a finished rid."""
+    backend = build_backend(replicas=1, kv_tokens=2048, max_budget=256)
+    prompt = np.random.default_rng(5).integers(1, cfg.vocab_size, 24)
+    ref = backend.submit(prompt.astype(np.int32), max_output=4).result(900.0)
+    fe, cli, th = _start_http(backend)
+    try:
+        h = cli.generate(prompt.tolist(), max_output=4)
+        assert h.result() == ref
+        assert h.rid == 1
+    finally:
+        _stop_http(fe, th)
 
 
 def test_http_sse_parity_with_inprocess(cfg, http_fe):
