@@ -20,11 +20,6 @@ per scheduler round, readbacks per round, and wall time. The paged engine must a
 prefill requests a decision names (for rounds within the ROW_BUCKETS row
 ladder; larger rounds add one dispatch per extra row group).
 
-``--profile-overhead`` serves one workload through the real paged engine
-twice — zero-sync overlapped pipeline vs the legacy sync-every-row hot path
-(``overlap=False``) — and reports rounds/sec, host-overhead fraction and
-device readback counts for both.
-
 ``--spec-k K`` serves one periodic workload through the real paged engine
 twice — n-gram speculative decoding at K vs plain decode at 0 — and records
 acceptance rate, tokens per verify row, rounds saved and the goodput delta
@@ -180,86 +175,6 @@ def engine_comparison(n_requests: int = 12, seed: int = 0) -> dict:
                  f"{st.token_readbacks / max(st.iterations, 1):.2f}",
                  "1.0 = zero-sync preserved under the streaming frontend")
     write_json("engine_comparison", results)
-    return results
-
-
-def profile_overhead(n_requests: int = 12, max_output: int = 32,
-                     seed: int = 0, repeats: int = 5) -> dict:
-    """Zero-sync hot-path A/B on the real paged engine: the overlapped
-    one-readback-per-round pipeline vs the legacy sync-every-row loop
-    (``overlap=False``), identical workload and prompts. Reports rounds/sec,
-    host-overhead fraction (host time / wall), SLO-violation rate and
-    device readback counts for both; greedy outputs must match exactly.
-    Each mode is JIT-warmed and then measured ``repeats`` times (best pass
-    reported — CI boxes are contended and single passes are noisy)."""
-    import numpy as np
-    from repro.configs import get_config
-    from repro.core import SlidingServeScheduler
-    from repro.serving.engine import ServingEngine
-    from repro.serving.request import Request
-
-    cfg = get_config("llama3.2-3b").smoke()
-    rng = np.random.default_rng(seed)
-    proto = [Request(rid=i, arrival=0.0,
-                     prompt_len=int(rng.integers(24, 96)),
-                     max_output=max_output,
-                     ttft_slo=30.0, tbt_slo=5.0) for i in range(n_requests)]
-    prompts = {r.rid: rng.integers(1, cfg.vocab_size, r.prompt_len).astype(np.int32)
-               for r in proto}
-    results, outputs = {}, {}
-    for label, overlap in (("overlap", True), ("sync_per_row", False)):
-        from repro.serving.engine import EngineStats
-        sched = SlidingServeScheduler(max_budget=512, max_iter_time=5.0)
-        eng = ServingEngine(cfg, sched, cache_mode="paged",
-                            kv_capacity_tokens=8192, overlap=overlap)
-        # warmup pass (same shapes, shifted rids): JIT compilation must not
-        # be attributed to either hot path — the A/B measures steady state.
-        warm = [dataclasses.replace(r, rid=r.rid + 10_000) for r in proto]
-        eng.serve(warm, {r.rid: prompts[r.rid - 10_000].copy() for r in warm},
-                  max_wall_s=600.0)
-        best = None
-        for rep in range(repeats):
-            off = rep * 20_000
-            eng.stats = EngineStats()
-            reqs = [dataclasses.replace(r, rid=r.rid + off) for r in proto]
-            out = eng.serve(reqs, {r.rid: prompts[r.rid - off].copy()
-                                   for r in reqs}, max_wall_s=600.0)
-            if rep == 0:
-                outputs[label] = {rid: toks for rid, toks
-                                  in out["outputs"].items() if rid < 10_000}
-            if best is None or out["wall"] < best[0]["wall"]:
-                best = (out, reqs)
-        out, reqs = best
-        st = out["stats"]
-        wall = max(out["wall"], 1e-9)
-        viol = sum(r.violations()["violated"] for r in reqs) / len(reqs)
-        results[label] = {
-            "finished": len(out["finished"]),
-            "wall_s": wall,
-            "rounds_per_s": st.iterations / wall,
-            "host_overhead_fraction": st.host_s / wall,
-            "sync_s": st.sync_s,
-            "dispatch_s": st.dispatch_s,
-            "token_readbacks": st.token_readbacks,
-            "readbacks_per_round": st.token_readbacks / max(st.iterations, 1),
-            "reused_table_uploads": st.reused_uploads,
-            "slo_violation_rate": viol,
-        }
-        emit(f"profile/{label}/rounds_per_s",
-             f"{results[label]['rounds_per_s']:.2f}", "")
-        emit(f"profile/{label}/host_overhead_fraction",
-             f"{results[label]['host_overhead_fraction']:.3f}",
-             "host time / wall")
-        emit(f"profile/{label}/readbacks_per_round",
-             f"{results[label]['readbacks_per_round']:.2f}",
-             "1.0 = zero-sync target" if overlap else "legacy per-row syncs")
-    assert outputs["overlap"] == outputs["sync_per_row"], \
-        "overlapped pipeline changed greedy outputs"
-    results["speedup_rounds_per_s"] = (results["overlap"]["rounds_per_s"]
-                                       / results["sync_per_row"]["rounds_per_s"])
-    emit("profile/speedup_rounds_per_s",
-         f"{results['speedup_rounds_per_s']:.3f}", "overlap vs sync-per-row")
-    write_json("profile_overhead", results)
     return results
 
 
@@ -612,8 +527,6 @@ def router_comparison(replicas: int = 2, seed: int = 0) -> dict:
 if __name__ == "__main__":
     if "--engine" in sys.argv:
         engine_comparison()
-    elif "--profile-overhead" in sys.argv:
-        profile_overhead()
     elif "--prefix-cache" in sys.argv:
         prefix_cache_comparison()
     elif "--spec-k" in sys.argv:

@@ -306,6 +306,7 @@ def paged_attention_fused(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, kv_pages)
     if partial:

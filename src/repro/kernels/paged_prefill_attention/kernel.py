@@ -350,6 +350,7 @@ def paged_prefill_attention_fused(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), row_pos.astype(jnp.int32),
       lengths.astype(jnp.int32), qf, kv_pages)
 

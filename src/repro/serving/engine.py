@@ -26,8 +26,7 @@ substrate. Two cache designs share one serve loop:
   pulled while round N+1's admission, scheduling and numpy batch assembly
   have already happened on the host. Block-table uploads are content-cached
   and reused across rounds. ``overlap=False`` restores the legacy
-  sync-every-row behaviour for A/B profiling (``bench_goodput
-  --profile-overhead``).
+  sync-every-row behaviour for A/B profiling.
 * **slot** (fallback for recurrent/MLA/enc-dec archs whose per-request state
   is not paged) — contiguous ``max_slots x max_len`` rows, per-request
   chunked prefill and lockstep ragged decode, as in the original engine.
@@ -60,6 +59,14 @@ surface one ``step()`` late, at the flush that reads the round's ids back.
 ``serve()`` is a thin offline compatibility wrapper that feeds a request
 list through the same ``step()`` loop; ``repro.serving.server`` hosts the
 streaming submit/cancel frontend.
+
+Each paged round's host phases are spans of ``EngineCore.spans``
+(``repro.serving.spans``): ``engine.round`` around ``step()``, and inside it
+``engine.admit``, ``engine.schedule``, ``engine.grow``, ``engine.assemble``,
+``engine.flush`` (child ``engine.readback``) and ``engine.dispatch.<kind>``
+(children ``engine.upload`` and ``engine.compile`` or ``engine.launch``).
+They are kept in memory, summed per name for operators (``GET /v1/stats``),
+and written, with their args, into a profile when one is recording.
 """
 from __future__ import annotations
 
@@ -85,6 +92,7 @@ from repro.models.model import (PAGED_KV_LAYOUT, RunCtx, Sampling,
 from repro.serving.block_allocator import BlockAllocator
 from repro.serving.drafter import DrafterBase, NGramDrafter
 from repro.serving.request import ReqState, Request
+from repro.serving.spans import Spans
 
 # chunk-length ladder for JIT shape bucketing; allocations above the top rung
 # are split across dispatches instead of being silently truncated.
@@ -161,11 +169,12 @@ class EngineStats:
                                   # in-flight leader to commit a shared prefix
     # ---- zero-sync hot-path accounting (paged mode) --------------------------
     token_readbacks: int = 0      # device->host token-id transfers
-    sync_s: float = 0.0           # wall time blocked waiting on the device
-    dispatch_s: float = 0.0       # wall time issuing (async) model dispatches
-    device_busy_s: float = 0.0    # wall covered by an in-flight round
-    host_s: float = 0.0           # wall with NO round in flight: unhidden
-                                  # host work + idle (the overlap target -> 0)
+    sync_s: float = 0.0           # wall in ``engine.readback`` spans
+    dispatch_s: float = 0.0       # wall in ``engine.dispatch.*`` spans
+    device_busy_s: float = 0.0    # wall time some round is in flight: from
+                                  # its dispatch to the end of its flush, on
+                                  # the host's clock (not the device's busy
+                                  # time, which only a profile shows)
     reused_uploads: int = 0       # block-table uploads served from device cache
     # ---- speculative decoding (paged mode, spec_k > 0) -----------------------
     spec_calls: int = 0           # fused verify dispatches
@@ -258,6 +267,7 @@ class EngineCore:
         self._cache_shardings = None
         self.params = init_params(cfg, jax.random.PRNGKey(seed))
         self.stats = EngineStats()
+        self.spans = Spans()
         self._tokens_out: Dict[int, List[int]] = {}
         self._seen_shapes = set()
         self._resumed: set = set()    # evicted mid-decode; re-prefill, no emit
@@ -664,14 +674,15 @@ class EngineCore:
             return True
         return False
 
-    def _admit(self) -> None:
+    def _admit(self) -> List[int]:
         """Move due arrivals into the admission queue, then admit while the
         free pool lasts (full-prompt + decode-reserve reservation). Admission
         order weights the request's named SLO class: latency-critical classes
         (``interactive``) go first, FIFO preserved within a class — a
         single-class workload therefore admits in exactly the legacy FIFO
-        order (the stable sort is a no-op)."""
+        order (the stable sort is a no-op). Returns the admitted rids."""
         paged = self.cache_mode == "paged"
+        admitted: List[int] = []
         while self._pending and self._pending[0][0] <= self._now():
             _, _, r = heapq.heappop(self._pending)
             self._queued.append(r)
@@ -728,6 +739,7 @@ class EngineCore:
                         self.stats.cache_hit_tokens += matched
                         self.stats.prompt_tokens += r.prompt_len
                     self._event(EventKind.ADMITTED, r.rid, self._now())
+                    admitted.append(r.rid)
                 else:
                     self._queued.append(r)
                     failures += 1
@@ -738,15 +750,29 @@ class EngineCore:
                     break
         self.stats.max_concurrency = max(self.stats.max_concurrency,
                                          len(self._active))
+        return admitted
 
     def step(self) -> List[EngineEvent]:
         """Run one scheduler round: admit, schedule, dispatch (≤2 fused model
         calls in paged mode), and flush the *previous* round's deferred
         readback. Returns the events that settled during this call; an idle
         step (nothing admitted or schedulable) flushes any in-flight round so
-        the final tokens always surface."""
+        the final tokens always surface. The round is the span
+        ``engine.round``; its args count live KV pages, queued and active
+        requests as it ends."""
+        self.spans.round += 1
+        with self.spans.span("engine.round", round=self.spans.round) as sp:
+            events = self._step()
+            if self.cache_mode == "paged":
+                sp.args["live_pages"] = self.alloc.live_blocks
+            sp.args["queued"] = len(self._queued)
+            sp.args["active"] = len(self._active)
+        return events
+
+    def _step(self) -> List[EngineEvent]:
         paged = self.cache_mode == "paged"
-        self._admit()
+        with self.spans.span("engine.admit") as sp:
+            sp.args["rids"] = self._admit()
         if not self._active:
             self._flush_round()         # device is idle anyway
             self._progress = "idle"
@@ -759,8 +785,9 @@ class EngineCore:
                       if r.state == ReqState.PREFILLING]
         decoding = [r for r in self._active if r.state == ReqState.DECODING]
         kv = self._kv_pressure() if paged else None
-        decision = self.sched.schedule(self._now(), waiting, prefilling,
-                                       decoding, kv=kv)
+        with self.spans.span("engine.schedule"):
+            decision = self.sched.schedule(self._now(), waiting, prefilling,
+                                           decoding, kv=kv)
         if decision is None:
             self._flush_round()
             self._progress = "no-decision"
@@ -1214,20 +1241,27 @@ class EngineCore:
         if fr is None:
             return
         self._inflight = None
-        t0 = time.perf_counter()
-        joined = fr.toks[0] if len(fr.toks) == 1 else jnp.concatenate(fr.toks)
-        if self.overlap:
-            vals = self._readback(joined)
-            toks = {idx: int(vals[idx]) for _, idx in fr.emits}
-        else:
-            # legacy profile: one scalar transfer per emitting row, like the
-            # pre-zero-sync engine's per-row ``int(jnp.argmax(logits[i]))``
-            # (verify-row spans transfer per row too in this mode).
-            toks = {idx: int(self._readback(joined[idx]))
-                    for _, idx in fr.emits}
-            if fr.spec_emits:
+        with self.spans.span("engine.flush"):
+            self._settle_round(fr)
+
+    def _settle_round(self, fr: _InflightRound) -> None:
+        """The work of :meth:`_flush_round`, inside its span."""
+        with self.spans.span("engine.readback") as sp:
+            joined = (fr.toks[0] if len(fr.toks) == 1
+                      else jnp.concatenate(fr.toks))
+            if self.overlap:
                 vals = self._readback(joined)
-        self.stats.sync_s += time.perf_counter() - t0
+                toks = {idx: int(vals[idx]) for _, idx in fr.emits}
+            else:
+                # legacy profile: one scalar transfer per emitting row,
+                # like the pre-zero-sync engine's per-row
+                # ``int(jnp.argmax(logits[i]))`` (verify-row spans
+                # transfer per row too in this mode).
+                toks = {idx: int(self._readback(joined[idx]))
+                        for _, idx in fr.emits}
+                if fr.spec_emits:
+                    vals = self._readback(joined)
+        self.stats.sync_s += sp.dur_ns / 1e9
         t_done = self._now()
         by_rid = {r.rid: (r, k, wf, fin) for r, k, wf, fin in fr.stamped}
         for rid, idx in fr.emits:
@@ -1297,9 +1331,6 @@ class EngineCore:
                 self._event(EventKind.FINISHED, rid, t_done,
                             reason=finished_reason)
         latency = time.perf_counter() - fr.t_dispatch
-        # dispatch->flush intervals are disjoint (the next dispatch happens
-        # only after this flush), so their sum is the wall time covered by an
-        # in-flight round; the remainder is unhidden host overhead.
         self.stats.device_busy_s += latency
         self.sched.observe(fr.executed_batch, latency, kv=self._kv_pressure())
 
@@ -1460,45 +1491,66 @@ class EngineCore:
         """Issue one fused dispatch (async under JAX dispatch); returns the
         device token-id vector — [Rb] for decode/chunk, [Rb*(Lb+1)] payload
         for spec. The RNG nonce advances per dispatch so sampled rounds stay
-        reproducible (the sequence of dispatches is deterministic)."""
-        nonce = self._to_dev(np.int32(self._sample_nonce))
-        self._sample_nonce += 1
-        if asm["kind"] == "decode":
-            self._note_shape(("decode", asm["Rb"], asm["nb"]))
-            toks, self.cache = self._jit_decode_fused(
-                self.params, self._to_dev(asm["tokens"]), self.cache,
-                self._to_dev(asm["lengths"]),
-                self._upload_cached(("decode", asm.get("group", 0)),
-                                    asm["tables"]),
-                self._to_dev(asm["slots"].astype(np.int32)), nonce)
+        reproducible (the sequence of dispatches is deterministic).
+
+        Spans: ``engine.dispatch.<kind>`` around it all, ``engine.upload``
+        around the host→device operand uploads, then ``engine.compile`` around
+        the jit call the first time a step shape is met (tracing, compiling
+        or loading the program) and ``engine.launch`` on every later call."""
+        kind = asm["kind"]
+        if kind == "decode":
+            key = ("decode", asm["Rb"], asm["nb"])
+            rows = tokens = int(np.count_nonzero(asm["lengths"]))
+        else:
+            key = (kind, asm["Rb"], asm["Lb"], asm["nb"])
+            n = np.asarray(asm["row_lens"]) - np.asarray(asm["row_pos"])
+            rows, tokens = int(np.count_nonzero(n)), int(n.sum())
+        with self.spans.span(f"engine.dispatch.{kind}", Rb=asm["Rb"],
+                             Lb=asm.get("Lb", 1), nb=asm["nb"], rows=rows,
+                             tokens=tokens) as sp:
+            with self.spans.span("engine.upload"):
+                nonce = self._to_dev(np.int32(self._sample_nonce))
+                toks_in = self._to_dev(asm["tokens"])
+                tables = self._upload_cached((kind, asm.get("group", 0)),
+                                             asm["tables"])
+                if kind == "decode":
+                    fn = self._jit_decode_fused
+                    rest = (self._to_dev(asm["lengths"]), tables,
+                            self._to_dev(asm["slots"].astype(np.int32)),
+                            nonce)
+                else:
+                    fn = (self._jit_spec_fused if kind == "spec"
+                          else self._jit_chunk_fused)
+                    rest = (self._to_dev(asm["row_pos"]),
+                            self._to_dev(asm["row_lens"]), tables,
+                            self._to_dev(
+                                asm["slots"].reshape(-1).astype(np.int32)))
+                    rest += ((nonce,) if kind == "spec"
+                             else (self._to_dev(asm["logits_at"]), nonce))
+            self._sample_nonce += 1
+            if self._note_shape(key):
+                call = self.spans.span("engine.compile", key=key)
+            else:
+                call = self.spans.span("engine.launch")
+            with call:
+                toks, self.cache = fn(self.params, toks_in, self.cache, *rest)
+        self.stats.dispatch_s += sp.dur_ns / 1e9
+        if kind == "decode":
             self.stats.decode_calls += 1
-        elif asm["kind"] == "spec":
-            self._note_shape(("spec", asm["Rb"], asm["Lb"], asm["nb"]))
-            toks, self.cache = self._jit_spec_fused(
-                self.params, self._to_dev(asm["tokens"]), self.cache,
-                self._to_dev(asm["row_pos"]), self._to_dev(asm["row_lens"]),
-                self._upload_cached(("spec", asm.get("group", 0)),
-                                    asm["tables"]),
-                self._to_dev(asm["slots"].reshape(-1).astype(np.int32)),
-                nonce)
+        elif kind == "spec":
             self.stats.spec_calls += 1
         else:
-            self._note_shape(("chunk", asm["Rb"], asm["Lb"], asm["nb"]))
-            toks, self.cache = self._jit_chunk_fused(
-                self.params, self._to_dev(asm["tokens"]), self.cache,
-                self._to_dev(asm["row_pos"]), self._to_dev(asm["row_lens"]),
-                self._upload_cached(("chunk", asm.get("group", 0)),
-                                    asm["tables"]),
-                self._to_dev(asm["slots"].reshape(-1).astype(np.int32)),
-                self._to_dev(asm["logits_at"]), nonce)
             self.stats.prefill_calls += 1
         self._round_calls += 1
         return toks
 
-    def _note_shape(self, key) -> None:
-        if key not in self._seen_shapes:
-            self._seen_shapes.add(key)
-            self.stats.compiled_shapes += 1
+    def _note_shape(self, key) -> bool:
+        """Record a step shape; True the first time it is met."""
+        if key in self._seen_shapes:
+            return False
+        self._seen_shapes.add(key)
+        self.stats.compiled_shapes += 1
+        return True
 
     # =========================================================================
     # offline compatibility wrapper (shared by both cache modes)
@@ -1524,7 +1576,6 @@ class EngineCore:
             for r in requests
         }
         self._t0 = time.perf_counter()
-        busy0 = self.stats.device_busy_s    # stats accumulate across serve()s
         done0 = len(self._done)
         for r in sorted(requests, key=lambda r: r.arrival):
             self.add_request(r, prompts[r.rid])
@@ -1558,10 +1609,6 @@ class EngineCore:
         # TOKEN/FINISHED events for long-gone requests.
         self.flush()
         wall = self._now()
-        # host_s is per-serve (this call's wall minus this call's in-flight
-        # coverage); the other counters are cumulative across serve() calls.
-        self.stats.host_s = max(
-            wall - (self.stats.device_busy_s - busy0), 0.0)
         return {
             "finished": self._done[done0:],
             "unfinished": [r for r in requests if r.state != ReqState.FINISHED],
@@ -1610,72 +1657,82 @@ class EngineCore:
             return r.rid in self.alloc.owners and r.state not in (
                 ReqState.FINISHED, ReqState.ABORTED)
 
-        pressure = self._spec_pressure() if spec_on else False
-        decode_rows: List[Request] = []
-        spec_rows: List[Tuple[Request, np.ndarray]] = []
-        prefill_rows: List[Tuple[Request, int]] = []
-        for r, n in decision.alloc:
-            if not is_live(r):
-                continue
-            if r.state == ReqState.DECODING:
-                drafts = self._propose_drafts(r, pressure) if spec_on else None
-                if drafts is not None and self._grow_or_evict(
-                        r, self._length[r.rid] + 1 + len(drafts), protected):
-                    spec_rows.append((r, drafts))
-                elif self._grow_or_evict(r, self._length[r.rid] + 1,
-                                         protected):
-                    decode_rows.append(r)
-            else:
-                n_exec = min(n, r.remaining_prefill())
-                if n_exec <= 0:
+        n_ev = len(self._events)
+        with self.spans.span("engine.grow") as sp:
+            pressure = self._spec_pressure() if spec_on else False
+            decode_rows: List[Request] = []
+            spec_rows: List[Tuple[Request, np.ndarray]] = []
+            prefill_rows: List[Tuple[Request, int]] = []
+            for r, n in decision.alloc:
+                if not is_live(r):
                     continue
-                start = self._length.get(r.rid, 0)
-                # admission reserved the full remaining prompt, so this grow
-                # is a no-op today; it stays so a future partial-reservation
-                # admission policy still allocates (or skips) correctly.
-                if not self._grow_or_evict(r, start + n_exec, protected):
-                    continue
-                prefill_rows.append((r, n_exec))
-        decode_rows = [r for r in decode_rows if is_live(r)]
-        spec_rows = [(r, d) for r, d in spec_rows if is_live(r)]
-        prefill_rows = [(r, n) for r, n in prefill_rows if is_live(r)]
+                if r.state == ReqState.DECODING:
+                    drafts = (self._propose_drafts(r, pressure) if spec_on
+                              else None)
+                    if drafts is not None and self._grow_or_evict(
+                            r, self._length[r.rid] + 1 + len(drafts),
+                            protected):
+                        spec_rows.append((r, drafts))
+                    elif self._grow_or_evict(r, self._length[r.rid] + 1,
+                                             protected):
+                        decode_rows.append(r)
+                else:
+                    n_exec = min(n, r.remaining_prefill())
+                    if n_exec <= 0:
+                        continue
+                    start = self._length.get(r.rid, 0)
+                    # admission reserved the full remaining prompt, so this
+                    # grow is a no-op today; it stays so a future
+                    # partial-reservation admission policy still allocates
+                    # (or skips) correctly.
+                    if not self._grow_or_evict(r, start + n_exec, protected):
+                        continue
+                    prefill_rows.append((r, n_exec))
+            decode_rows = [r for r in decode_rows if is_live(r)]
+            spec_rows = [(r, d) for r, d in spec_rows if is_live(r)]
+            prefill_rows = [(r, n) for r, n in prefill_rows if is_live(r)]
+            sp.args["evicted"] = [e.rid for e in self._events[n_ev:]
+                                  if e.kind == EventKind.EVICTED]
         self._last_round_evictions = self.alloc.evictions - ev0
         if not decode_rows and not spec_rows and not prefill_rows:
             return []
 
         # ---- host-side numpy assembly (device still busy with round N) ------
-        executed: List[Tuple] = []
-        decode_asms: List[dict] = []
-        if decode_rows:
-            ctxs = {r.rid: r.context_len() for r in decode_rows}
-            for i in range(0, len(decode_rows), ROW_BUCKETS[-1]):
-                asm = self._assemble_decode(decode_rows[i:i + ROW_BUCKETS[-1]])
-                asm["group"] = i // ROW_BUCKETS[-1]
-                decode_asms.append(asm)
-            executed += [(r, 1, ctxs[r.rid]) for r in decode_rows]
-        spec_asms: List[dict] = []
-        if spec_rows:
-            self.stats.spec_rounds += 1
-            for i in range(0, len(spec_rows), ROW_BUCKETS[-1]):
-                asm = self._assemble_spec(spec_rows[i:i + ROW_BUCKETS[-1]])
-                asm["group"] = i // ROW_BUCKETS[-1]
-                spec_asms.append(asm)
-            for r, drafts in spec_rows:
-                self._round_spec_rids.add(r.rid)
-                executed.append((r, 1 + len(drafts), r.context_len(),
-                                 len(drafts)))
-        if spec_on:
-            self._feed_spec_signals(
-                sum(len(d) for _, d in spec_rows),
-                len(decode_rows) + len(spec_rows))
-        chunk_asms: List[dict] = []
-        if prefill_rows:
-            ctxs = {r.rid: r.context_len() for r, _ in prefill_rows}
-            chunk_asms = self._assemble_prefill(prefill_rows, prompts)
-            executed += [(r, n, ctxs[r.rid]) for r, n in prefill_rows]
-            for r, n in prefill_rows:
-                self.stats.prefill_tokens += n
-                self._commit(r.rid)   # freeze pages this round fills
+        with self.spans.span("engine.assemble"):
+            executed: List[Tuple] = []
+            decode_asms: List[dict] = []
+            if decode_rows:
+                ctxs = {r.rid: r.context_len() for r in decode_rows}
+                for i in range(0, len(decode_rows), ROW_BUCKETS[-1]):
+                    asm = self._assemble_decode(
+                        decode_rows[i:i + ROW_BUCKETS[-1]])
+                    asm["group"] = i // ROW_BUCKETS[-1]
+                    decode_asms.append(asm)
+                executed += [(r, 1, ctxs[r.rid]) for r in decode_rows]
+            spec_asms: List[dict] = []
+            if spec_rows:
+                self.stats.spec_rounds += 1
+                for i in range(0, len(spec_rows), ROW_BUCKETS[-1]):
+                    asm = self._assemble_spec(
+                        spec_rows[i:i + ROW_BUCKETS[-1]])
+                    asm["group"] = i // ROW_BUCKETS[-1]
+                    spec_asms.append(asm)
+                for r, drafts in spec_rows:
+                    self._round_spec_rids.add(r.rid)
+                    executed.append((r, 1 + len(drafts), r.context_len(),
+                                     len(drafts)))
+            if spec_on:
+                self._feed_spec_signals(
+                    sum(len(d) for _, d in spec_rows),
+                    len(decode_rows) + len(spec_rows))
+            chunk_asms: List[dict] = []
+            if prefill_rows:
+                ctxs = {r.rid: r.context_len() for r, _ in prefill_rows}
+                chunk_asms = self._assemble_prefill(prefill_rows, prompts)
+                executed += [(r, n, ctxs[r.rid]) for r, n in prefill_rows]
+                for r, n in prefill_rows:
+                    self.stats.prefill_tokens += n
+                    self._commit(r.rid)   # freeze pages this round fills
 
         # ---- the round's single sync: round N's token ids -------------------
         # (no-op when spec_on already flushed above — never a second sync)
@@ -1720,7 +1777,6 @@ class EngineCore:
             emits += [(rid, off + row) for rid, row in asm["emit_rows"]
                       if rid in self._reqs]
             off += asm["Rb"]
-        self.stats.dispatch_s += time.perf_counter() - t_disp
         self._inflight = _InflightRound(toks=toks, emits=emits,
                                         t_dispatch=t_disp,
                                         spec_emits=spec_emits)

@@ -360,14 +360,16 @@ class InferenceServer:
 
     def stats_snapshot(self) -> Dict:
         """JSON-able operational snapshot (the HTTP ``GET /v1/stats`` body):
-        EngineStats counters, prefix-cache accounting, per-class metrics and
-        live queue/outstanding-work gauges."""
+        EngineStats counters, prefix-cache accounting, per-class metrics,
+        live queue/outstanding-work gauges, and per engine span name its
+        count, total and longest milliseconds (``spans``)."""
         core = self.core
         summ = self.summary()
         return {
             "engine": dataclasses.asdict(summ.pop("stats")),
             "cache_info": core.cache_info(),
             "sharding": core.shard_info(),
+            "spans": core.spans.totals(),
             "queue_depth": core.queue_depth,
             "outstanding_tokens": core.outstanding_tokens(),
             "draining": self._draining,

@@ -403,5 +403,8 @@ def test_http_stats_and_draining_rejection(cfg, http_fe):
     assert st["engine"]["iterations"] > 0
     assert st["engine"]["token_readbacks"] == st["engine"]["iterations"]
     assert "cache_info" in st and "per_class" in st
+    rounds = st["spans"]["engine.round"]
+    assert rounds["count"] >= st["engine"]["iterations"]
+    assert 0 < rounds["max_ms"] <= rounds["total_ms"]
     assert cli.load()["outstanding_tokens"] == 0
     assert cli.prefix_feed()["next"] > 0    # commits were exported

@@ -165,7 +165,16 @@ def test_single_token_readback_per_round():
     assert len(calls) == st.token_readbacks == st.iterations, (
         len(calls), st.token_readbacks, st.iterations)
     assert st.max_concurrency > 1      # rounds really were batched
-    assert st.sync_s > 0.0 and st.host_s > 0.0
+    assert st.sync_s > 0.0
+    # the readback and dispatch timers are the engine spans' durations
+    tot = eng.spans.totals()
+    assert tot["engine.readback"]["count"] == st.token_readbacks
+    assert abs(tot["engine.readback"]["total_ms"] - 1e3 * st.sync_s) < 1e-6
+    dispatches = [v for k, v in tot.items() if k.startswith("engine.dispatch.")]
+    assert sum(v["count"] for v in dispatches) == (st.decode_calls
+                                                   + st.prefill_calls)
+    assert abs(sum(v["total_ms"] for v in dispatches)
+               - 1e3 * st.dispatch_s) < 1e-6
 
     # legacy sync-every-row mode: same tokens, strictly more transfers
     eng2, out2 = _serve(cfg, prompts, spec, cache_mode="paged",

@@ -58,9 +58,13 @@ def _spec(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _check(compiled):
-    assert "tpu_custom_call" in compiled.as_text(), \
+def _check(compiled, kernel):
+    """The program holds the Pallas kernel, under its own name (the device
+    trace tells the decode and prefill kernels apart by it)."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, \
         "the compiled program holds no Pallas kernel"
+    assert f"%{kernel}" in text, f"no kernel named {kernel!r}"
 
 
 @pytest.mark.parametrize("partial", [False, True], ids=["full", "partial"])
@@ -73,7 +77,7 @@ def test_decode_kernel_compiles_for_v5e(one_chip, rows, depth, partial):
         _spec(one_chip, (rows, HKV * G, D), jnp.bfloat16),
         _spec(one_chip, (HKV, POOL_PAGES, 2, PAGE, D), jnp.bfloat16),
         _spec(one_chip, (rows, TABLE)),
-        _spec(one_chip, (rows,))).compile())
+        _spec(one_chip, (rows,))).compile(), "paged_decode_attention")
 
 
 # Sq=2: a speculative verify row; 16: the smallest chunk bucket; 2048: the
@@ -90,4 +94,4 @@ def test_prefill_kernel_compiles_for_v5e(one_chip, rows, sq, depth, partial):
         _spec(one_chip, (HKV, POOL_PAGES, 2, PAGE, D), jnp.bfloat16),
         _spec(one_chip, (rows, TABLE)),
         _spec(one_chip, (rows,)),
-        _spec(one_chip, (rows,))).compile())
+        _spec(one_chip, (rows,))).compile(), "paged_prefill_attention")
