@@ -15,8 +15,6 @@ computed in float8 puts first, at the same positions.
 """
 from __future__ import annotations
 
-import importlib.util
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,15 +25,6 @@ SAMPLE_TOKENS = 384      # served tokens compared per run, at least
 SAMPLE_MAX = 12          # requests in the sample, at most
 ROWS = 1024              # positions read per sequence (outputs are shorter)
 SEQ_STEP = 2048          # reference sequences are padded to a multiple
-
-
-def load_reference(conf: dict):
-    path = spec.HERE / "configs" / f"{conf['reference']}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"slobench.configs.{conf['reference']}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod
 
 
 def pick(finished: list, seed: int) -> list:
@@ -89,7 +78,7 @@ def gaps(conf: dict, w, sample: list, control: bool = False) -> tuple:
     lies below the reference's best at its position; with ``control``, also
     the gap of the token that the float8 reference puts first at the same
     position. Returns ``(program, control or None)`` as arrays."""
-    ref_mod = load_reference(conf)
+    ref_mod = spec.config_module(conf, "reference")
     prog, ctrl = [], []
     for prompt, tokens, _ in sample:
         full, rows, served, m = _padded(prompt, tokens)

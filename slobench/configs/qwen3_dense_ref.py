@@ -4,12 +4,13 @@ The forward pass as published, in ``jax.numpy`` and float32 with every
 matrix product at ``Precision.HIGHEST``: token embedding; per layer
 ``x += o(attn(rope(norm_q(q(n1(x)))), rope(norm_k(k(n1(x)))), v(n1(x))))``
 with grouped-query causal softmax attention, then
-``x += down(silu(gate(n2(x))) * up(n2(x)))``; a final RMSNorm and the tied
-LM head. RMSNorm is ``x / sqrt(mean(x^2) + eps) * g``; RoPE rotates the two
+``x += down(silu(gate(n2(x))) * up(n2(x)))``; a final RMSNorm and the LM
+head: the embedding's transpose where ``tie_word_embeddings`` holds, else
+its own matrix ``lm_head``. RMSNorm is ``x / sqrt(mean(x^2) + eps) * g``; RoPE rotates the two
 halves of each head (``rotate_half``) at ``rope_theta``. No cache, no pages,
 no kernels, no batching: one sequence at a time, teacher-forced, attention
 computed in blocks of queries so that long sequences fit beside the weights.
-Weights are the benchmark's own (``slobench/weights.py`` naming), read in
+Weights are the benchmark's own (``qwen3_dense.shapes`` naming), read in
 their served bfloat16 and widened layer by layer.
 
 ``quant="fp8"`` is the precision control: every linear layer (the LM head
@@ -107,7 +108,8 @@ def _forward(w, tokens, rows, conf_items, quant):
 
     x, _ = jax.lax.scan(layer, x, layers)
     x = _rms(x[rows], w["final_norm"], eps)
-    return _linear(x, w["embed"].T, quant)
+    head = w["embed"].T if conf["tie_word_embeddings"] else w["lm_head"]
+    return _linear(x, head, quant)
 
 
 def logits(w, conf: dict, tokens, rows, quant=None):

@@ -93,7 +93,7 @@ def main(argv=None) -> int:
         return 2
     from slobench import spec
     conf = spec.load_config_by_name(argv[0])
-    cfg = spec.model_config(conf)
+    cfg = spec.family(conf).model_config(conf)
     eng = conf["engine"]
     max_pages = spec.pow2(-(-spec.max_context(conf) // eng["page_size"]))
     for tokens in argv[1:]:

@@ -1,7 +1,10 @@
 """Roofline share of the paged decode attention kernel (the Pallas
-``tpu_custom_call``, named ``_fused_kernel``, inside ``jit_decode_fused``), in %."""
+``tpu_custom_call`` named ``paged_decode_attention``, inside
+``jit_decode_fused``), in %."""
 from slobench import rooflines
+
+KERNEL = r"paged_decode_attention.*tpu_custom_call"
 
 
 def read(run):
-    return rooflines.share(run, "jit_decode_fused", "decode")
+    return rooflines.share(run, "jit_decode_fused", "decode", KERNEL)

@@ -3,8 +3,9 @@
     python3 -m slobench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
-configuration and a traffic mix. The run makes the configuration's weights
-from the seed on the chip, builds the serving engine as ``launch/serve.py``
+configuration and a traffic mix. The run makes the weights of the
+configuration's model family (``slobench/configs/<family>.py``) from the
+seed on the chip, builds the serving engine as ``launch/serve.py``
 does (``SlidingServeScheduler``, paged KV, prefix cache, overlapped rounds,
 greedy, no speculation) behind ``InferenceServer``, compiles or loads every
 step shape the mix lists, replays the mix open-loop through a lead-in, the
@@ -14,11 +15,14 @@ the configuration's plain reference (``slobench/check.py``).
 
 With ``--trace 0`` the result holds the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result holds its
-per-layer metrics. Lines before the last are labels; the last line of
-standard output is the result, and the numbers compared for ``correct``
-are also the last lines of standard error. Without a TPU, with fewer chips
-than the cell asks for, or outside a checkout of the repository, it exits
-non-zero and prints no result.
+per-layer metrics. Lines before the last are labels; a ``"phase"`` label
+is printed, and flushed, as each phase ends (start, weights, build, warm,
+lead_in, window, lead_out, trace_load with ``--trace 1``, read, check),
+with its seconds, so that a run stopped at a time limit shows where it
+stood. The last line of standard output is the result, and the numbers
+compared for ``correct`` are also the last lines of standard error.
+Without a TPU, with fewer chips than the cell asks for, or outside a
+checkout of the repository, it exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -91,6 +95,15 @@ def label(dev: dict, name: str, **fields) -> None:
                      default=float), flush=True)
 
 
+def phase(dev: dict, name: str, t0: float, **fields) -> float:
+    """Print the label of the phase ``name`` that began at ``t0`` and ends
+    now, with its seconds, so that a run stopped from outside shows how
+    far it came; returns now."""
+    now = time.perf_counter()
+    label(dev, "phase", phase=name, seconds=now - t0, **fields)
+    return now
+
+
 def require_chip(chips: int, need_chip: bool = True):
     """The JAX devices the cell runs on; Refused where they are missing."""
     if not (spec.CHECKOUT / "src" / "repro").is_dir():
@@ -146,30 +159,31 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     peak = peaks.get(d0.device_kind, {})
     counter = openloop.compile_counter()
 
-    t = time.perf_counter()
+    t = phase(dev, "start", T_START)
     w, params = weights.make(cell.conf, seed)
-    cfg = spec.model_config(cell.conf)
+    cfg = spec.family(cell.conf).model_config(cell.conf)
     weights.check_layout(cfg, params)
     jax.block_until_ready(params)
-    t_weights = time.perf_counter() - t
-    t = time.perf_counter()
+    t = phase(dev, "weights", t)
     server = hooks.build_server(cell.conf, cfg, params)
     core = server.core
-    t_build = time.perf_counter() - t
-    t = time.perf_counter()
+    t = phase(dev, "build", t)
     n_warm = hooks.warm(core, cell.mix["warm"])
-    t_warm = time.perf_counter() - t
+    t = phase(dev, "warm", t, shapes=n_warm)
     arrivals = traffic.make(cell.mix, seconds, seed, cell.conf["vocab_size"])
     probe = hooks.Probe(server, annotate=trace)
     c_setup = (counter.compiles, counter.traces)
-    label(dev, "setup", weights_s=t_weights, build_s=t_build, warm_s=t_warm,
-          warm_shapes=n_warm, compiles=c_setup[0], traced=c_setup[1],
+    label(dev, "setup", compiles=c_setup[0], traced=c_setup[1],
           kv_pool_tokens=cell.conf["kv_pool_tokens"], cache_dir=str(CACHE_DIR))
 
     marks = {}
 
     def on_window(edge):
         marks[edge] = (time.perf_counter(), counter.compiles, counter.traces)
+        if edge == "start":
+            phase(dev, "lead_in", marks["loop"])
+        else:
+            phase(dev, "window", marks["start"][0])
         if not trace:
             return
         # no round is in flight at either edge, so the trace holds the
@@ -182,20 +196,27 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         else:
             marks["trace1"] = time.perf_counter()
             jax.profiler.stop_trace()
+            marks["trace_stop_s"] = time.perf_counter() - marks["trace1"]
 
+    marks["loop"] = time.perf_counter()
     res = openloop.run(server, probe, arrivals,
                      lambda a: traffic.limits(cell.mix, a), seconds,
                      cell.mix["lead_in_s"], cell.mix["lead_out_s"], on_window)
+    phase(dev, "lead_out", marks["end"][0],
+          trace_stop_s=marks.get("trace_stop_s"))
     win = res["window"]
     setup_s = marks["start"][0] - T_START
     reqs = measure.from_events(server.events, res["meta"])
     data = RunData(cell.conf, reqs, win, res["end"], seconds, setup_s, probe,
                    core.alloc.num_blocks, peak)
     if trace:
+        t = time.perf_counter()
         path = tracefile.find(str(TRACE_DIR))
         data.trace = tracefile.load(path) if path else None
         data.trace_t0, data.trace_t1 = marks["trace0"], marks["trace1"]
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        phase(dev, "trace_load", t, ops=len(data.trace.ops) if data.trace
+              else None)
 
     w0, w1 = win.w0, win.w1
     ttft = measure.ttfts(reqs, w0, w1, res["end"])
@@ -230,6 +251,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     seen = hooks.seen_shapes(core)
     label(dev, "shapes", seen=len(seen), unwarmed=sorted(seen - warmed))
 
+    t = time.perf_counter()
     entries = cell.per_layer if trace else cell.e2e
     kind = "layers" if trace else "e2e"
     metrics = {}
@@ -249,6 +271,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         hi = max((e.end for e in ev), default=0)
         result["breakdown"] = {"device_ops": tracefile.top_ops(tr),
                                "idle_gaps": tracefile.idle_by_host(tr, lo, hi)}
+    phase(dev, "read", t, metrics=len(metrics))
 
     # ---- correctness, after the window, with the engine's state freed ----
     evicted = {r.rid: r.evictions for r in reqs}
@@ -269,7 +292,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     checks = {"logit_gap": {"value": gap, "limit": limit},
               "tokens_compared": {"value": n_tok, "limit": least}}
     result["correct"] = bool(gap <= limit and n_tok >= least)
-    label(dev, "check", seconds=time.perf_counter() - t, requests=len(sample),
+    phase(dev, "check", t, requests=len(sample),
           longest=max((len(p) + len(o) for p, o, _ in sample), default=0),
           evicted=sum(1 for *_, e in sample if e),
           program=check.summary(prog),

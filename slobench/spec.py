@@ -5,16 +5,20 @@ configuration (a JSON file under ``slobench/configs/``) and traffic mix (a
 JSON file under ``slobench/traffic/``). Each metric is read by a module of its
 own: ``slobench/e2e/<name>.py`` for end-to-end metrics and
 ``slobench/layers/<name>.py`` for per-layer metrics, each with a
-``read(run)`` function. Nothing here knows a cell by name.
+``read(run)`` function. A configuration names its model family and its
+plain reference, each a module under ``slobench/configs/`` (see
+:func:`family`). Nothing here knows a cell or a model by name.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 CHECKOUT = HERE.parent
+CONFIGS = HERE / "configs"
 
 
 def load_benchmark() -> dict:
@@ -68,11 +72,17 @@ def reader(kind: str, name: str):
     path = HERE / kind / f"{name}.py"
     if not path.exists():
         path = HERE / kind / f"{name.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"slobench.{kind}.{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path).read
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: Path):
+    """The module in the file ``path``, loaded once."""
+    mod_spec = importlib.util.spec_from_file_location(
+        f"slobench.{path.parent.name}.{path.stem.replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def pow2(n: int) -> int:
@@ -88,29 +98,25 @@ def max_context(conf: dict) -> int:
     return eng["max_request_tokens"] + eng["decode_reserve_tokens"]
 
 
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file that holds a
-    Qwen3-style dense decoder's published ``config.json`` keys."""
-    import jax.numpy as jnp
+def config_module(conf: dict, key: str):
+    """The module ``slobench/configs/<conf[key]>.py``."""
+    if key not in conf:
+        raise KeyError(f"configuration {conf.get('name')!r} has no {key!r} "
+                       f"key naming a module under slobench/configs/")
+    path = CONFIGS / f"{conf[key]}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {conf.get('name')!r}: no "
+                                f"{key} module {path}")
+    return _module(path)
 
-    from repro.configs.base import ATTN, DENSE, ModelConfig
-    unsupported = {"attention_bias": False, "use_sliding_window": False,
-                   "rope_scaling": None}
-    for key, want in unsupported.items():
-        if conf.get(key, want) != want:
-            raise ValueError(f"{conf['name']}: {key}={conf[key]!r} is not "
-                             f"a dense Qwen3 decoder")
-    if conf["model_type"] != "qwen3":
-        raise ValueError(f"{conf['name']}: model_type {conf['model_type']!r}")
-    dtype = {"bfloat16": jnp.bfloat16}[conf["torch_dtype"]]
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        layer_pattern=(ATTN,), ffn_pattern=(DENSE,),
-        rope_theta=float(conf["rope_theta"]), qk_norm=True,
-        tie_embeddings=conf["tie_word_embeddings"],
-        norm_eps=conf["rms_norm_eps"], activation=conf["hidden_act"],
-        dtype=dtype, param_dtype=dtype)
+
+def family(conf: dict):
+    """The configuration's model family, ``slobench/configs/<family>.py``:
+    ``model_config(conf)``, the program's ``ModelConfig``; ``shapes(conf)``,
+    the table of tensors ``weights.make`` draws (name -> (shape, kind,
+    std)); ``engine_params(conf, w, offsets)``, the engine's parameter tree
+    made of them; ``step_flops(conf, kind, meta)``, the model FLOPs of one
+    dispatch; and ``attention(conf, kind, meta)``, the (flops, bytes) of its
+    attention kernel summed over every layer of one dispatch. Only the
+    family and the reference read the model's own keys."""
+    return config_module(conf, "family")

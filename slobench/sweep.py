@@ -77,7 +77,8 @@ def main(argv=None) -> int:
     d0 = devices[0]
     dev = {"platform": d0.platform, "kind": d0.device_kind, "count": 1}
     w, params = weights.make(cell.conf, args.seed)
-    server = build_server(cell.conf, spec.model_config(cell.conf), params)
+    cfg = spec.family(cell.conf).model_config(cell.conf)
+    server = build_server(cell.conf, cfg, params)
     core = server.core
     mem = d0.memory_stats() or {}
     label(dev, "built", peak_bytes=mem.get("peak_bytes_in_use"),

@@ -16,12 +16,15 @@ LIMIT = 0.2                  # CPU readings 0.03-0.05; fp8 control 0.45-1.4
 SEED = 2 ** 31 + 11          # wider than 32 signed bits
 
 
-def tiny_cell():
+def tiny_cell(config: str = "qwen3-1.7b", **keys):
+    """A tiny cell of the configuration file ``config``, with ``keys``
+    changed last."""
     from slobench.run import Cell
-    conf = json.loads((spec.HERE / "configs" / "qwen3-1.7b.json").read_text())
+    conf = json.loads((spec.HERE / "configs" / f"{config}.json").read_text())
     conf.update(name="tiny", hidden_size=64, intermediate_size=128,
                 num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                 num_hidden_layers=2, vocab_size=512, initializer_range=0.2)
+    conf.update(keys)
     conf["engine"] = dict(conf["engine"], max_request_tokens=320)
     conf["kv_pool_tokens"] = 2048
     conf["check"] = {"max_logit_gap": LIMIT, "min_tokens": 16}
@@ -42,11 +45,11 @@ def tiny_cell():
     return Cell("tiny.cell", 1, conf, mix, e2e, [])
 
 
-def tiny_run(**kw):
+def tiny_run(cell=None, trace: bool = False, **kw):
     from slobench.run import run_cell
     with mock.patch.object(check, "SEQ_STEP", 256):
-        return run_cell(tiny_cell(), SEED, SECONDS, False, need_chip=False,
-                        persistent_cache=False, **kw)
+        return run_cell(cell or tiny_cell(), SEED, SECONDS, trace,
+                        need_chip=False, persistent_cache=False, **kw)
 
 
 def test_sound_run_is_correct_and_precision_control_is_not():
@@ -80,3 +83,19 @@ def test_refuses_without_a_tpu():
     from slobench.run import Refused, run_cell
     with pytest.raises(Refused):
         run_cell(tiny_cell(), 1, 1.0, False, persistent_cache=False)
+
+
+def test_traced_run_labels_every_phase_as_it_ends(capsys):
+    bench = spec.load_benchmark()
+    cell = tiny_cell()
+    cell.per_layer = spec.metrics_for(bench, bench["workloads"][0]["name"],
+                                      "per_layer")
+    res = tiny_run(cell, trace=True)
+    labels = [json.loads(line) for line in capsys.readouterr().out.splitlines()
+              if line.startswith('{"label": "phase"')]
+    assert [x["phase"] for x in labels] == [
+        "start", "weights", "build", "warm", "lead_in", "window", "lead_out",
+        "trace_load", "read", "check"]
+    assert all(x["seconds"] >= 0 for x in labels)
+    assert res["correct"], res["checks"]
+    assert res["device"]["busy_s"] > 0 and "breakdown" in res

@@ -178,11 +178,17 @@ def idle_by_host(tr: Trace, t0: int, t1: int, k: int = 10) -> List[list]:
         gaps.append((cur, t1))
     spans = sorted(tr.host, key=lambda h: (h.start, -h.dur))
     acc: Dict[str, int] = defaultdict(int)
+    # the gaps come in order, so a span that ends before one gap starts
+    # overlaps no later gap: keep only the spans begun and not yet ended
+    live: List[Event] = []
+    nxt = 0
     for gs, ge in gaps:
+        while nxt < len(spans) and spans[nxt].start < ge:
+            live.append(spans[nxt])
+            nxt += 1
+        live = [h for h in live if h.end > gs]
         covered = 0
-        for h in spans:
-            if h.start >= ge:
-                break
+        for h in live:
             ov = min(h.end, ge) - max(h.start, gs)
             if ov > 0:
                 acc[h.name] += ov

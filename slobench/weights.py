@@ -1,16 +1,19 @@
-"""Random weights of a dense Qwen3 decoder, made on the device from the seed.
+"""Random weights of a configuration's model, made on the device from the seed.
 
-One jitted call draws every tensor in bfloat16 (the type it is served in) and
-returns two views of the same matrices:
+The configuration's family (``slobench/configs/<family>.py``) gives the table
+of tensors to draw, ``shapes(conf)``: name -> (shape, kind, std). One jitted
+call draws every tensor in bfloat16 (the type it is served in), each from
+the seed's key folded in with its rank in the table's sorted names:
 
-* ``w``: the benchmark's own naming, after Hugging Face's ``Qwen3ForCausalLM``
-  (per-layer tensors stacked on a leading layer axis; matrices stored
-  ``[in, out]`` so that ``y = x @ w``; RMSNorm gains ``g`` multiply the
-  normalised input). The plain reference reads only this view.
-* ``params``: the tree the serving engine loads (``repro.models.model``),
-  which holds each RMSNorm gain as an offset from one. The gains are drawn
-  in ``[0.5, 1.9]``, where ``g - 1`` is exact in bfloat16, so both views
-  compute with the same numbers.
+* ``"matrix"``: normal(0, std);
+* ``"gain"``: an RMSNorm gain, 1 + normal(0, std) clipped to [0.5, 1.9],
+  where ``g - 1`` is exact in bfloat16. The call also returns each gain's
+  offset from one, which the engine holds in its place, so that both
+  views compute with the same numbers.
+
+``make`` returns two views of the same matrices: ``w``, the benchmark's own
+naming, which the plain reference reads, and the tree the serving engine
+loads, which the family's ``engine_params`` makes of ``w`` and the offsets.
 """
 from __future__ import annotations
 
@@ -18,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-GAIN_STD = 0.1
+from slobench import spec
 
 
 def seed_key(seed: int):
@@ -29,60 +32,31 @@ def seed_key(seed: int):
                                     impl="threefry2x32")
 
 
-def shapes(conf: dict) -> dict:
-    """name -> (shape, kind) with kind "matrix" or "gain"."""
-    L, d = conf["num_hidden_layers"], conf["hidden_size"]
-    H, Hkv, D = (conf["num_attention_heads"], conf["num_key_value_heads"],
-                 conf["head_dim"])
-    F, V = conf["intermediate_size"], conf["vocab_size"]
-    return {
-        "embed": ((V, d), "matrix"),
-        "in_norm": ((L, d), "gain"),
-        "q": ((L, d, H * D), "matrix"),
-        "k": ((L, d, Hkv * D), "matrix"),
-        "v": ((L, d, Hkv * D), "matrix"),
-        "q_norm": ((L, D), "gain"),
-        "k_norm": ((L, D), "gain"),
-        "o": ((L, H * D, d), "matrix"),
-        "post_norm": ((L, d), "gain"),
-        "gate": ((L, d, F), "matrix"),
-        "up": ((L, d, F), "matrix"),
-        "down": ((L, F, d), "matrix"),
-        "final_norm": ((d,), "gain"),
-    }
-
-
 def make(conf: dict, seed: int):
     """``(w, params)`` for the configuration file ``conf`` (see the module
     docstring), made in one jitted call on the default device."""
-    if not conf["tie_word_embeddings"]:
-        raise ValueError(f"{conf['name']}: untied LM head not drawn here")
-    std = float(conf["initializer_range"])
-    table = shapes(conf)
+    fam = spec.family(conf)
+    table = fam.shapes(conf)
 
     @jax.jit
     def draw(key):
         w = {}
-        for i, (name, (shape, kind)) in enumerate(sorted(table.items())):
+        for i, (name, (shape, kind, std)) in enumerate(sorted(table.items())):
             k = jax.random.fold_in(key, i)
             if kind == "matrix":
                 w[name] = (jax.random.normal(k, shape, jnp.float32)
                            * std).astype(jnp.bfloat16)
-            else:
-                g = 1.0 + GAIN_STD * jax.random.normal(k, shape, jnp.float32)
+            elif kind == "gain":
+                g = 1.0 + std * jax.random.normal(k, shape, jnp.float32)
                 w[name] = jnp.clip(g, 0.5, 1.9).astype(jnp.bfloat16)
+            else:
+                raise ValueError(f"{name}: no draw of kind {kind!r}")
         offsets = {n: w[n] - jnp.bfloat16(1.0)
-                   for n, (_, kind) in table.items() if kind == "gain"}
+                   for n, (_, kind, _) in table.items() if kind == "gain"}
         return w, offsets
 
     w, off = draw(seed_key(seed))
-    attn = {"ln": off["in_norm"], "wq": w["q"], "wk": w["k"], "wv": w["v"],
-            "wo": w["o"], "q_norm": off["q_norm"], "k_norm": off["k_norm"]}
-    ffn = {"ln": off["post_norm"], "wi_gate": w["gate"], "wi_up": w["up"],
-           "wo": w["down"]}
-    params = {"embed": w["embed"], "final_norm": off["final_norm"],
-              "stacks": [[{"attn": attn, "ffn": ffn}]]}
-    return w, params
+    return w, fam.engine_params(conf, w, off)
 
 
 def check_layout(cfg, params) -> None:
